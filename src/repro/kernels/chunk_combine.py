@@ -8,54 +8,63 @@ untouched (chunks the local rank already owns).  Doing this with separate
 select/add ops costs three HBM round-trips over the gradient buffer; the
 fused kernel does one read of each operand and one write.
 
-Grid: one program per chunk tile; per-chunk control (segment membership,
-accumulate-vs-overwrite) arrives as scalar-prefetch-style int32 operands in
-SMEM-friendly (1,1) blocks.
+Layout: each chunk is viewed as ``(rows, 128)`` lane rows, so a data block
+is ``(block_rows, 128)`` with ``block_rows`` a multiple of the sublane tile
+— the (8, 128) tiling the TPU compiler requires.  Grid: (chunk, row
+block).  The per-chunk control (segment membership, accumulate vs
+overwrite) is scalar-prefetched into SMEM as two whole ``(C,)`` int32
+arrays and read with the chunk's grid index.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+LANES = 128
+
 
 def _combine_kernel(seg_ref, acc_ref, local_ref, recv_ref, o_ref):
-    seg = seg_ref[0] != 0
-    acc = acc_ref[0] != 0
-    local = local_ref[...]
-    recv = recv_ref[...]
-    comb = jnp.where(acc, local + recv, recv)
-    o_ref[...] = jnp.where(seg, comb, local)
+    c = pl.program_id(0)
+    seg = seg_ref[c] != 0
+    acc = acc_ref[c] != 0
+
+    @pl.when(jnp.logical_not(seg))
+    def _():
+        o_ref[...] = local_ref[...]
+
+    @pl.when(jnp.logical_and(seg, acc))
+    def _():
+        o_ref[...] = local_ref[...] + recv_ref[...]
+
+    @pl.when(jnp.logical_and(seg, jnp.logical_not(acc)))
+    def _():
+        o_ref[...] = recv_ref[...]
 
 
 def chunk_combine_pallas(
-    local: jax.Array,               # (C, M)
-    recv: jax.Array,                # (C, M)
+    local: jax.Array,               # (C, R, 128)
+    recv: jax.Array,                # (C, R, 128)
     seg_mask: jax.Array,            # (C,) int32/bool
     accumulate: jax.Array,          # (C,) int32/bool
     *,
-    tile: int = 512,
-    interpret: bool = True,
+    block_rows: int,
+    interpret: bool = False,
 ) -> jax.Array:
-    C, M = local.shape
-    assert M % tile == 0, f"M={M} must be a multiple of tile={tile}"
-    nm = M // tile
-    seg = seg_mask.astype(jnp.int32)
-    acc = accumulate.astype(jnp.int32)
+    C, R, lanes = local.shape
+    assert lanes == LANES, f"last dim {lanes} must be {LANES}"
+    assert R % block_rows == 0, f"R={R} must be a multiple of {block_rows}"
+    block = pl.BlockSpec((None, block_rows, LANES), lambda c, r, *_: (c, r, 0))
     return pl.pallas_call(
         _combine_kernel,
-        grid=(C, nm),
-        in_specs=[
-            pl.BlockSpec((1,), lambda c, m: (c,)),
-            pl.BlockSpec((1,), lambda c, m: (c,)),
-            pl.BlockSpec((1, tile), lambda c, m: (c, m)),
-            pl.BlockSpec((1, tile), lambda c, m: (c, m)),
-        ],
-        out_specs=pl.BlockSpec((1, tile), lambda c, m: (c, m)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(C, R // block_rows),
+            in_specs=[block, block],
+            out_specs=block,
+        ),
         out_shape=jax.ShapeDtypeStruct(local.shape, local.dtype),
         interpret=interpret,
-    )(seg, acc, local, recv)
+    )(seg_mask.astype(jnp.int32), accumulate.astype(jnp.int32), local, recv)

@@ -3,7 +3,10 @@
 ``impl`` selection:
   * "pallas"     — pl.pallas_call, TPU lowering (interpret=False);
   * "interpret"  — same kernel body executed in interpret mode (CPU CI);
-  * "reference"  — the pure-jnp oracle from ``ref.py``.
+  * "reference"  — the pure-jnp oracle from ``ref.py``;
+  * ``None``     — the default: "pallas" on a TPU backend, "interpret" on
+                   any other, so a kernel the TPU compiler refuses fails
+                   loudly on the chip instead of running the interpreter.
 """
 
 from __future__ import annotations
@@ -14,9 +17,17 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
-from .chunk_combine import chunk_combine_pallas
+from .chunk_combine import LANES, chunk_combine_pallas
 from .flash_attention import flash_attention_pallas
 from .lru_scan import lru_scan_pallas
+
+
+def default_impl() -> str:
+    return "pallas" if jax.default_backend() == "tpu" else "interpret"
+
+
+def _round_up(x: int, multiple: int) -> int:
+    return -(-x // multiple) * multiple
 
 
 def _pad_axis(x, axis, multiple):
@@ -41,9 +52,10 @@ def flash_attention(
     scale: float | None = None,
     q_block: int = 128,
     kv_block: int = 128,
-    impl: str = "interpret",
+    impl: str | None = None,
 ):
     """(B,Tq,KVH,G,D) x (B,Tk,KVH,D)^2 -> (B,Tq,KVH,G,D)."""
+    impl = impl or default_impl()
     if impl == "reference":
         return ref.reference_attention(
             q, k, v, causal=causal, window=window, prefix_len=prefix_len,
@@ -62,23 +74,38 @@ def flash_attention(
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "impl"))
-def chunk_combine(local, recv, seg_mask, accumulate, *, tile: int = 512,
-                  impl: str = "interpret"):
-    """Fused R2CCL stage-2 merge; (C,M) buffers, (C,) masks."""
+def chunk_combine(local, recv, seg_mask, accumulate, *, tile: int = 64 * 1024,
+                  impl: str | None = None):
+    """Fused R2CCL stage-2 merge; (C,M) buffers, (C,) masks.
+
+    Each chunk is padded and viewed as 128-lane rows; one grid step merges
+    about ``tile`` elements of one chunk, in a row block rounded to the
+    dtype's sublane tile (8 rows of f32, 16 of bf16)."""
+    impl = impl or default_impl()
     if impl == "reference":
         return ref.reference_chunk_combine(local, recv, seg_mask, accumulate)
-    lp, m = _pad_axis(local, 1, tile)
-    rp, _ = _pad_axis(recv, 1, tile)
-    out = chunk_combine_pallas(lp, rp, seg_mask, accumulate, tile=min(tile, lp.shape[1]),
+    C, M = local.shape
+    sublanes = 8 * max(1, 4 // local.dtype.itemsize)
+    rows = -(-M // LANES)
+    block_rows = min(_round_up(max(tile // LANES, 1), sublanes),
+                     _round_up(rows, sublanes))
+    R = _round_up(rows, block_rows)
+
+    def lay(x):
+        return jnp.pad(x, ((0, 0), (0, R * LANES - M))).reshape(C, R, LANES)
+
+    out = chunk_combine_pallas(lay(local), lay(recv), seg_mask, accumulate,
+                               block_rows=block_rows,
                                interpret=(impl != "pallas"))
-    return out[:, :m]
+    return out.reshape(C, R * LANES)[:, :M]
 
 
 @functools.partial(jax.jit, static_argnames=("time_tile", "width_tile",
                                              "batch_tile", "impl"))
 def lru_scan(a, x, *, time_tile: int = 128, width_tile: int = 128,
-             batch_tile: int = 8, impl: str = "interpret"):
+             batch_tile: int = 8, impl: str | None = None):
     """RG-LRU hidden states with h0=0; (B,T,W) -> (B,T,W) float32."""
+    impl = impl or default_impl()
     if impl == "reference":
         h0 = jnp.zeros((a.shape[0], a.shape[2]), jnp.float32)
         return ref.reference_lru_scan(a, x, h0)
@@ -102,10 +129,11 @@ def lru_scan(a, x, *, time_tile: int = 128, width_tile: int = 128,
 
 
 @functools.partial(jax.jit, static_argnames=("time_tile", "impl"))
-def wkv_scan(r, k, v, w, u, *, time_tile: int = 64, impl: str = "interpret"):
+def wkv_scan(r, k, v, w, u, *, time_tile: int = 64, impl: str | None = None):
     """RWKV-6 WKV recurrence: r/k/w (BH,T,K), v (BH,T,V), u (BH,K)
     -> (BH,T,V) float32, S_0 = 0."""
     from .wkv_scan import wkv_scan_pallas
+    impl = impl or default_impl()
     if impl == "reference":
         return ref.reference_wkv(r, k, v, w, u)
     T = r.shape[1]

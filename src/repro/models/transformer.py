@@ -271,6 +271,13 @@ def init_model(key, cfg: ModelConfig):
     return params, axes
 
 
+def init_params(key, cfg: ModelConfig):
+    """``init_model``'s params from one compiled program.  Run op by op,
+    the init compiles one small program per random draw, which on a TPU
+    costs about a minute for a 32-layer model."""
+    return jax.jit(lambda k: init_model(k, cfg)[0])(key)
+
+
 def init_caches(cfg: ModelConfig, batch: int, context_len: int,
                 window_override=None, dtype=jnp.bfloat16):
     """Cache pytree matching the model structure (None in train mode)."""
