@@ -183,6 +183,14 @@ def train_parity_phase(cfg, *, steps: int = 3, seq_len: int = 512,
     return total
 
 
+def timed(name: str, phase, *args) -> float:
+    """Run one phase and log its wall seconds, set-up included."""
+    t0 = time.perf_counter()
+    out = phase(*args)
+    log(f"{name} phase: {time.perf_counter() - t0:.1f} s wall")
+    return out
+
+
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--four-chips", action="store_true",
@@ -209,9 +217,11 @@ def main() -> None:
         check(len(devices) == 4, f"--four-chips needs 4 chips, found {len(devices)}")
         mesh = jax.make_mesh((4,), ("data",),
                              axis_types=(jax.sharding.AxisType.Auto,))
-        compile_s = all_reduce_phase(mesh) + train_parity_phase(cfg)
+        compile_s = (timed("all_reduce", all_reduce_phase, mesh)
+                     + timed("train parity", train_parity_phase, cfg))
     else:
-        compile_s = train_phase(cfg) + serve_phase(cfg)
+        compile_s = (timed("train", train_phase, cfg)
+                     + timed("serve", serve_phase, cfg))
     log(f"compile seconds this run: {compile_s:.1f} (cache had {before} "
         f"entries); phases took {time.perf_counter() - t0:.1f} s")
     print(json.dumps({"ok": True, "device": {
