@@ -19,6 +19,7 @@ The paper's contribution as a composable library:
                  timestamped failure injection, rollback accounting)
   comm_sim     — alpha-beta cluster simulator (SimAI-lite) for evaluation,
                  with mode="event" delegating to event_sim
+  spans        — names of the profiler's host spans and device scopes
 """
 
 from . import (  # noqa: F401

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from . import spans
 from .allreduce import build_r2ccl_all_reduce
 from .recursive import build_recursive_all_reduce
 from .schedule import (
@@ -60,32 +61,38 @@ def execute_schedule(x: jax.Array, sched: ChunkSchedule, axis_name: str) -> jax.
     rank = lax.axis_index(axis_name)
     orig = x.shape[0]
     pad = (-orig) % sched.num_chunks
-    if pad:
-        x = jnp.concatenate([x, jnp.zeros((pad,), x.dtype)])
-    chunks = x.reshape(sched.num_chunks, -1)
+    with jax.named_scope(spans.PACK):
+        if pad:
+            x = jnp.concatenate([x, jnp.zeros((pad,), x.dtype)])
+        chunks = x.reshape(sched.num_chunks, -1)
 
     for step in sched.steps:
-        dst_mask = jnp.asarray(_dst_mask(step, n))[rank]
+        with jax.named_scope(spans.MERGE):
+            dst_mask = jnp.asarray(_dst_mask(step, n))[rank]
         if step.whole_buffer:
             recv = lax.ppermute(chunks, axis_name, step.perm)
-            if step.accumulate:
-                # non-destinations receive zeros -> adding is a no-op
-                chunks = chunks + recv
-            else:
-                chunks = jnp.where(dst_mask, recv, chunks)
+            with jax.named_scope(spans.MERGE):
+                if step.accumulate:
+                    # non-destinations receive zeros -> adding is a no-op
+                    chunks = chunks + recv
+                else:
+                    chunks = jnp.where(dst_mask, recv, chunks)
         else:
             send_map = jnp.asarray(np.maximum(np.array(step.send_chunk), 0))
             recv_map = jnp.asarray(np.maximum(np.array(step.recv_chunk), 0))
-            payload = jnp.take(chunks, send_map[rank], axis=0)
+            with jax.named_scope(spans.MERGE):
+                payload = jnp.take(chunks, send_map[rank], axis=0)
             recv = lax.ppermute(payload, axis_name, step.perm)
-            ridx = recv_map[rank]
-            cur = jnp.take(chunks, ridx, axis=0)
-            new = cur + recv if step.accumulate else recv
-            upd = jnp.where(dst_mask, new, cur)
-            chunks = lax.dynamic_update_index_in_dim(chunks, upd, ridx, axis=0)
+            with jax.named_scope(spans.MERGE):
+                ridx = recv_map[rank]
+                cur = jnp.take(chunks, ridx, axis=0)
+                new = cur + recv if step.accumulate else recv
+                upd = jnp.where(dst_mask, new, cur)
+                chunks = lax.dynamic_update_index_in_dim(chunks, upd, ridx, axis=0)
 
-    out = chunks.reshape(-1)
-    return out[:orig] if pad else out
+    with jax.named_scope(spans.PACK):
+        out = chunks.reshape(-1)
+        return out[:orig] if pad else out
 
 
 def execute_program(x: jax.Array, prog: CollectiveProgram, axis_name: str) -> jax.Array:
@@ -96,9 +103,13 @@ def execute_program(x: jax.Array, prog: CollectiveProgram, axis_name: str) -> ja
     for i, seg in enumerate(prog.segments):
         end = total if i == len(prog.segments) - 1 else start + int(round(seg.frac * total))
         end = min(max(end, start), total)
-        outs.append(execute_schedule(x[start:end], seg.schedule, axis_name))
+        with jax.named_scope(spans.segment_scope(seg.schedule.name)):
+            with jax.named_scope(spans.PACK):
+                part = x[start:end]
+            outs.append(execute_schedule(part, seg.schedule, axis_name))
         start = end
-    return jnp.concatenate(outs) if len(outs) > 1 else outs[0]
+    with jax.named_scope(spans.PACK):
+        return jnp.concatenate(outs) if len(outs) > 1 else outs[0]
 
 
 # ---------------------------------------------------------------------------

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.base import ModelConfig
+from repro.core import spans
 from . import layers as L
 from . import mla as MLA
 from . import moe as MOE
@@ -104,20 +105,21 @@ def _apply_layer(params, cfg: ModelConfig, kind: str, x, *, cache, mode,
             window = window_override
         else:
             window = window_override or a.sliding_window
-        if a.kind == "mla":
-            y, new_cache = MLA.mla_attention(
-                params["attn"], h, num_heads=a.num_heads,
-                qk_nope_head_dim=a.qk_nope_head_dim,
-                qk_rope_head_dim=a.qk_rope_head_dim,
-                v_head_dim=a.v_head_dim, rope_theta=a.rope_theta,
-                cache=cache, mode=mode)
-        else:
-            y, new_cache = L.gqa_attention(
-                params["attn"], h, num_heads=a.num_heads,
-                num_kv_heads=a.num_kv_heads, head_dim=a.head_dim,
-                rope_theta=a.rope_theta, use_rope=a.use_rope,
-                causal=a.causal, window=window, prefix_len=prefix_len,
-                logit_cap=a.logit_softcap, cache=cache, mode=mode)
+        with jax.named_scope(spans.ATTENTION):
+            if a.kind == "mla":
+                y, new_cache = MLA.mla_attention(
+                    params["attn"], h, num_heads=a.num_heads,
+                    qk_nope_head_dim=a.qk_nope_head_dim,
+                    qk_rope_head_dim=a.qk_rope_head_dim,
+                    v_head_dim=a.v_head_dim, rope_theta=a.rope_theta,
+                    cache=cache, mode=mode)
+            else:
+                y, new_cache = L.gqa_attention(
+                    params["attn"], h, num_heads=a.num_heads,
+                    num_kv_heads=a.num_kv_heads, head_dim=a.head_dim,
+                    rope_theta=a.rope_theta, use_rope=a.use_rope,
+                    causal=a.causal, window=window, prefix_len=prefix_len,
+                    logit_cap=a.logit_softcap, cache=cache, mode=mode)
         x = x + y.astype(x.dtype)
     elif kind == "rglru":
         y, new_cache = RG.rglru_block(params["rglru"], h,
@@ -133,16 +135,17 @@ def _apply_layer(params, cfg: ModelConfig, kind: str, x, *, cache, mode,
         raise ValueError(kind)
 
     h2 = norm_fn(params["norm2"], x)
-    if "moe" in params:
-        y2, aux = MOE.moe_ffn(params["moe"], h2,
-                              num_experts=cfg.moe.num_experts,
-                              top_k=cfg.moe.top_k,
-                              capacity_factor=cfg.moe.capacity_factor,
-                              activation=cfg.activation,
-                              router_aux_weight=cfg.moe.router_aux_weight,
-                              expert_sharding=cfg.moe.expert_axis)
-    else:
-        y2 = L.mlp(params["mlp"], h2, cfg.activation)
+    with jax.named_scope(spans.FFN):
+        if "moe" in params:
+            y2, aux = MOE.moe_ffn(params["moe"], h2,
+                                  num_experts=cfg.moe.num_experts,
+                                  top_k=cfg.moe.top_k,
+                                  capacity_factor=cfg.moe.capacity_factor,
+                                  activation=cfg.activation,
+                                  router_aux_weight=cfg.moe.router_aux_weight,
+                                  expert_sharding=cfg.moe.expert_axis)
+        else:
+            y2 = L.mlp(params["mlp"], h2, cfg.activation)
     return x + y2.astype(x.dtype), new_cache, aux
 
 

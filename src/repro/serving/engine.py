@@ -30,8 +30,10 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
+from repro.core import spans
 from repro.core.comm_sim import (
     DEJAVU_OVERHEAD_RANGE,
     R2CCL_MIGRATION_LATENCY,
@@ -105,6 +107,7 @@ class ServingEngine:
         self.cache_dtype = cache_dtype
         self.failure_state = FailureState()
         self.failovers = 0
+        self.batches = 0                 # run so far; names a batch's spans
         # steady-state replication tax for DejaVu-style KV streaming
         self.dejavu_tax = float(np.mean(DEJAVU_OVERHEAD_RANGE))
         # The r2ccl hiccup is the recovery pipeline's ledger total, derived
@@ -164,22 +167,34 @@ class ServingEngine:
         """Serve a batch, optionally injecting ``failure`` at decode step
         ``fail_at_step``.  Returns per-request latency accounting in
         *virtual* time (real compute + modeled network events)."""
+        self.batches += 1
+        batch_id = self.batches
+        B = len(requests)
+        max_new = max(r.max_new_tokens for r in requests)
+        with TraceAnnotation(spans.SERVE_BATCH, batch=batch_id, size=B,
+                             new_tokens=max_new):
+            return self._run_batch(requests, batch_id, max_new,
+                                   fail_at_step, failure)
+
+    def _run_batch(self, requests: list[Request], batch_id: int, max_new: int,
+                   fail_at_step: int | None,
+                   failure: Failure | None) -> list[RequestResult]:
         cfg = self.cfg
         B = len(requests)
-        T = max(len(r.prompt) for r in requests)
-        toks = np.zeros((B, T), np.int32)
-        for i, r in enumerate(requests):
-            toks[i, T - len(r.prompt):] = r.prompt    # left-pad
-        max_new = max(r.max_new_tokens for r in requests)
-
-        caches = init_caches(cfg, B, self.context_len, dtype=self.cache_dtype)
-        batch = {"tokens": jnp.asarray(toks)}
+        with TraceAnnotation(spans.SERVE_ALLOC, batch=batch_id):
+            T = max(len(r.prompt) for r in requests)
+            toks = np.zeros((B, T), np.int32)
+            for i, r in enumerate(requests):
+                toks[i, T - len(r.prompt):] = r.prompt    # left-pad
+            caches = init_caches(cfg, B, self.context_len, dtype=self.cache_dtype)
+            batch = {"tokens": jnp.asarray(toks)}
 
         vtime = 0.0
-        t0 = self.clock()
-        next_tok, caches = self.prefill(self.params, batch, caches)
-        next_tok.block_until_ready()
-        prefill_time = self.clock() - t0
+        with TraceAnnotation(spans.SERVE_PREFILL, batch=batch_id):
+            t0 = self.clock()
+            next_tok, caches = self.prefill(self.params, batch, caches)
+            next_tok.block_until_ready()
+            prefill_time = self.clock() - t0
         vtime += prefill_time
         ttft = vtime
         failovers = 0
@@ -217,16 +232,21 @@ class ServingEngine:
                         vtime += R2CCL_MIGRATION_LATENCY
                     rate = self._degraded_rate()
                     failovers += 1
-            t0 = self.clock()
-            next_tok, caches = self.decode(self.params, next_tok, caches)
-            next_tok.block_until_ready()
-            dt = self.clock() - t0
-            base = dt * (1.0 + (self.dejavu_tax if self.strategy == "dejavu" else 0.0))
-            decode_times.append(base / rate)
-            vtime += base / rate
-            for i in range(B):
-                if len(generated[i]) < requests[i].max_new_tokens:
-                    generated[i].append(int(next_tok[i]))
+            with TraceAnnotation(spans.SERVE_DECODE, batch=batch_id, step=step):
+                t0 = self.clock()
+                with TraceAnnotation(spans.SERVE_DISPATCH):
+                    next_tok, caches = self.decode(self.params, next_tok, caches)
+                with TraceAnnotation(spans.SERVE_BLOCK):
+                    next_tok.block_until_ready()
+                dt = self.clock() - t0
+                base = dt * (1.0 + (self.dejavu_tax if self.strategy == "dejavu" else 0.0))
+                decode_times.append(base / rate)
+                vtime += base / rate
+                unfinished = [i for i in range(B)
+                              if len(generated[i]) < requests[i].max_new_tokens]
+                with TraceAnnotation(spans.SERVE_READBACK, reads=len(unfinished)):
+                    for i in unfinished:
+                        generated[i].append(int(next_tok[i]))
             step += 1
 
         self.failovers += failovers

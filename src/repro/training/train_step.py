@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
+from repro.core import spans
 from repro.core.collectives import sync_gradients
 from repro.core.planner import CommConfig
 from repro.models import apply_model
@@ -87,6 +88,7 @@ def make_train_step(
         total, metrics = compute_loss(params, cfg, batch)
         return total, metrics
 
+    @jax.named_scope(spans.OPTIMIZER)
     def apply_updates(state: TrainState, grads, metrics):
         lr_scale = cosine_with_warmup(state.step, warmup_steps=warmup_steps,
                                       total_steps=total_steps)
@@ -113,23 +115,24 @@ def make_train_step(
     def sharded_grads(params, batch):
         (_, metrics), grads = jax.value_and_grad(
             loss_for_grad, has_aux=True)(params, batch)
-        # Wire dtype: ship gradients in bf16 (the XLA-native path fuses the
-        # cast into its all-reduce; the explicit schedule must do the same
-        # or pay 2x the ring bytes).
-        wire_t = jnp.bfloat16 if comm.comm_dtype == "bfloat16" else jnp.float32
-        orig_dtypes = jax.tree_util.tree_map(lambda g: g.dtype, grads)
-        grads = jax.tree_util.tree_map(lambda g: g.astype(wire_t), grads)
-        # Intra-pod sync with the configured (possibly failure-aware)
-        # schedule; inter-pod combine with an explicit ring.
-        grads = sync_gradients(grads, data_axes[-1], mean=True, **comm.kwargs())
-        for ax in data_axes[:-1]:
-            grads = sync_gradients(grads, ax, mode="ring" if comm.mode != "xla"
-                                   else "xla", mean=True, g=comm.devices_per_node)
-        grads = jax.tree_util.tree_map(
-            lambda g, t: g.astype(t), grads, orig_dtypes)
-        metrics = jax.tree_util.tree_map(
-            lambda m: jax.lax.pmean(m, tuple(data_axes)), metrics)
-        return grads, metrics
+        with jax.named_scope(spans.SYNC):
+            # Wire dtype: ship gradients in bf16 (the XLA-native path fuses the
+            # cast into its all-reduce; the explicit schedule must do the same
+            # or pay 2x the ring bytes).
+            wire_t = jnp.bfloat16 if comm.comm_dtype == "bfloat16" else jnp.float32
+            orig_dtypes = jax.tree_util.tree_map(lambda g: g.dtype, grads)
+            grads = jax.tree_util.tree_map(lambda g: g.astype(wire_t), grads)
+            # Intra-pod sync with the configured (possibly failure-aware)
+            # schedule; inter-pod combine with an explicit ring.
+            grads = sync_gradients(grads, data_axes[-1], mean=True, **comm.kwargs())
+            for ax in data_axes[:-1]:
+                grads = sync_gradients(grads, ax, mode="ring" if comm.mode != "xla"
+                                       else "xla", mean=True, g=comm.devices_per_node)
+            grads = jax.tree_util.tree_map(
+                lambda g, t: g.astype(t), grads, orig_dtypes)
+            metrics = jax.tree_util.tree_map(
+                lambda m: jax.lax.pmean(m, tuple(data_axes)), metrics)
+            return grads, metrics
 
     def train_step(state: TrainState, batch):
         spec_batch = jax.tree_util.tree_map(lambda _: batch_spec, batch)
