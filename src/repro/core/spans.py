@@ -24,8 +24,10 @@ What reads each name (``chipbench/metrics/``, from a traced run):
 ``r2ccl.serve.dispatch``  the decode call until it returns:
                           ``dispatch_ms.serve`` (self time per decode step)
 ``r2ccl.serve.block``     ``block_until_ready`` on the step; names idle gaps
-``r2ccl.serve.readback``  the per-request device-to-host reads (``reads``):
-                          ``readback_ms.serve`` (self time per decode step)
+``r2ccl.serve.readback``  the step's tokens to the host in one transfer
+                          (``transfers``), kept for the unfinished requests
+                          (``reads``): ``readback_ms.serve`` (self time per
+                          decode step)
 ``attention``             the GQA/MLA branch of a layer: ``attention_ms.train``
 ``ffn``                   the MLP/MoE of a layer: ``ffn_ms.train``
 ``sync``                  gradient wire casts, the collective, the metrics

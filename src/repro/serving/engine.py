@@ -199,7 +199,9 @@ class ServingEngine:
         ttft = vtime
         failovers = 0
 
-        generated = [[int(next_tok[i])] for i in range(B)]
+        # One device->host transfer of the (B,) token vector, never one
+        # read per request: each read would dispatch its own gather.
+        generated = [[t] for t in jax.device_get(next_tok).tolist()]
         decode_times: list[float] = []
         rate = 1.0
         step = 0
@@ -244,9 +246,11 @@ class ServingEngine:
                 vtime += base / rate
                 unfinished = [i for i in range(B)
                               if len(generated[i]) < requests[i].max_new_tokens]
-                with TraceAnnotation(spans.SERVE_READBACK, reads=len(unfinished)):
+                with TraceAnnotation(spans.SERVE_READBACK, reads=len(unfinished),
+                                     transfers=1):
+                    host = jax.device_get(next_tok)
                     for i in unfinished:
-                        generated[i].append(int(next_tok[i]))
+                        generated[i].append(int(host[i]))
             step += 1
 
         self.failovers += failovers

@@ -1,12 +1,14 @@
 """Serving engine: batched decode, failure strategies, latency accounting."""
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.failures import Failure, FailureType
-from repro.models import get_smoke_config, init_model
+from repro.models import get_smoke_config, init_caches, init_model
 from repro.serving import Request, ServingEngine
+from repro.serving.engine import make_decode_fn, make_prefill_fn
 
 
 @pytest.fixture(scope="module")
@@ -29,6 +31,57 @@ def test_greedy_decode_deterministic(setup):
     r2 = eng.run_batch(_reqs(cfg))
     assert r1[0].tokens == r2[0].tokens
     assert len(r1[0].tokens) == 6
+
+
+def _greedy_per_request(cfg, params, reqs, context_len):
+    """The batch decoded by a plain greedy loop that reads each request's
+    token from the device on its own."""
+    T = max(len(r.prompt) for r in reqs)
+    toks = np.zeros((len(reqs), T), np.int32)
+    for i, r in enumerate(reqs):
+        toks[i, T - len(r.prompt):] = r.prompt
+    caches = init_caches(cfg, len(reqs), context_len, dtype=jnp.float32)
+    tok, caches = make_prefill_fn(cfg)(params, {"tokens": jnp.asarray(toks)},
+                                       caches)
+    decode = make_decode_fn(cfg)
+    out = [[int(tok[i])] for i in range(len(reqs))]
+    for _ in range(max(r.max_new_tokens for r in reqs) - 1):
+        tok, caches = decode(params, tok, caches)
+        for i, r in enumerate(reqs):
+            if len(out[i]) < r.max_new_tokens:
+                out[i].append(int(tok[i]))
+    return out
+
+
+@pytest.mark.parametrize("new", [(3, 6), (5, 5, 5)])
+@pytest.mark.parametrize("strategy,fail_at", [("r2ccl", None), ("restart", 2)])
+def test_one_token_transfer_per_step(setup, monkeypatch, new, strategy, fail_at):
+    """``run_batch`` copies the first token and each decode step's tokens
+    to the host in one transfer each, not one per request, and serves the
+    tokens a per-request greedy loop reads."""
+    cfg, params = setup
+    rng = np.random.default_rng(0)
+    reqs = [Request(prompt=rng.integers(0, cfg.vocab_size, 12), max_new_tokens=n)
+            for n in new]
+    eng = ServingEngine(cfg, params, context_len=64, strategy=strategy)
+    fail = Failure(FailureType.NIC_HARDWARE, 0, 0) if fail_at is not None else None
+    eng.run_batch(reqs, fail_at_step=fail_at, failure=fail)   # compiles
+    array_type = type(jnp.zeros(()))
+    to_host = array_type._value                 # every jax.Array -> numpy copy
+    transfers = []
+
+    def counted(self):
+        transfers.append(self.shape)
+        return to_host.fget(self)
+
+    monkeypatch.setattr(array_type, "_value", property(counted))
+    results = eng.run_batch(reqs, fail_at_step=fail_at, failure=fail)
+    monkeypatch.undo()
+    # the prefill's token, then one per decode step, each the whole batch's
+    assert transfers == [(len(new),)] * max(new)
+    assert [r.tokens for r in results] == _greedy_per_request(cfg, params, reqs, 64)
+    assert [len(r.tokens) for r in results] == list(new)
+    assert all(r.failovers == (fail_at is not None) for r in results)
 
 
 def test_r2ccl_continues_through_failure(setup):

@@ -98,6 +98,8 @@ def test_run_batch_writes_each_span(model, tmp_path, new):
     reads = [a["reads"] for _, _, _, a in by[spans.SERVE_READBACK]]
     assert reads == [sum(n > j + 1 for n in new) for j in range(steps)]
     assert sum(reads) == sum(len(r.tokens) - 1 for r in results)
+    # ... all from one device-to-host transfer of the step's tokens
+    assert [a["transfers"] for _, _, _, a in by[spans.SERVE_READBACK]] == [1] * steps
     # every span lies inside the batch; each decode step holds its three
     assert all(b0 <= s <= e <= b1 for _, s, e, _ in found)
     for (_, s, e, _), *kids in zip(by[spans.SERVE_DECODE], by[spans.SERVE_DISPATCH],
